@@ -7,12 +7,17 @@ engines (see :mod:`.base` for the contract and how to register new ones):
 * ``"scalar"`` — the exact Alg-2/Alg-3 oracle, one row at a time;
 * ``"numpy"``  — vectorized (B,) state advance, zero-dependency default
   (alias: ``"batched"``, the pre-refactor name);
-* ``"jax"``    — jit'd ``lax.while_loop`` sweep, float64 via scoped
-  ``enable_x64`` (lazy: registered on first lookup);
+* ``"jax"``    — jit'd ``lax.while_loop`` sweep at float64 inside the
+  scoped :func:`.jax_runtime.x64` (emulated float64 on a TPU; lazy:
+  registered on first lookup);
 * ``"pallas"`` — the fused Pallas kernel
   (:mod:`repro.kernels.placement_step`), blocks tiled through VMEM
-  (lazy; interpret mode off-TPU);
-* ``"auto"``   — best available of the above.
+  (lazy; float32 on a TPU, float64 interpret mode elsewhere);
+* ``"auto"``   — best available of the above (see
+  :func:`.base.resolve_engine`).
+
+``chip_smoke.py`` at the repository root holds both device engines'
+plans to the numpy engine and the scalar oracle on a TPU.
 """
 
 from .base import (

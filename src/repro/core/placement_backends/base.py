@@ -618,7 +618,8 @@ def resolve_engine(engine: str) -> str:
 
     ``"auto"`` picks the best available backend: the fused Pallas kernel on
     a TPU host, the jit'd jax sweep when jax is importable, the numpy block
-    engine otherwise.
+    engine otherwise.  On a TPU it picks only an engine that ``chip_smoke.py``
+    has run there with every plan identical to the oracle's.
     """
     engine = _ALIASES.get(engine, engine)
     if engine != "auto":

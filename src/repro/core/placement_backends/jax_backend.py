@@ -9,9 +9,12 @@ rows sweeps in a single device call with no per-step host round-trip.
 Bit-compatibility with the scalar oracle: the step arithmetic (defined
 once in :func:`repro.kernels.ref.placement_sweep_ref`) replays the same
 float64 add/sub chains in the same order — no multiply-add pairs, so XLA
-cannot FMA-contract them — and runs under a scoped ``enable_x64`` so the
-global jax float32 default (which the model/training substrate relies on)
-is untouched.
+cannot FMA-contract them — and runs under the scoped
+:func:`~repro.core.placement_backends.jax_runtime.x64`, so the global jax
+float32 default (which the model/training substrate relies on) is
+untouched.  On a TPU the same float64 program runs with XLA's float64
+emulation; on a TPU v5 lite every plan of ``chip_smoke.py`` was identical
+to the numpy engine's and the scalar oracle's.
 
 Block shapes are padded to the next power of two, bounding recompilation
 to O(log B) specializations per (n_t, n_f) topology; padded rows are
@@ -53,6 +56,7 @@ from .base import (
     survivor_batch_tables,
     survivor_tables,
 )
+from .jax_runtime import configure_compile_cache, x64
 
 __all__ = ["JaxPlacementBackend", "resolve_shard"]
 
@@ -138,20 +142,19 @@ def _jitted_batch_sweep(n_shards: int):
     if n_shards <= 1:
         return jax.jit(placement_sweep_batch_ref, static_argnames=("repay_init",))
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("i",))
 
     def sweep(shares, iis, t_slr, t_cfg, n_t_eff, n_f_eff, resume_cost, *, repay_init):
-        return shard_map(
+        return jax.shard_map(
             functools.partial(placement_sweep_batch_ref, repay_init=repay_init),
             mesh=mesh,
             in_specs=(P("i"), P("i"), P("i"), P("i"), P("i"), P("i"), P()),
             out_specs=(P("i"), P("i"), P("i"), P("i")),
-            # jax has no replication rule for while_loop; every output is
-            # instance-axis partitioned anyway, so the check adds nothing.
-            check_rep=False,
+            # Every output is instance-axis partitioned, so the varying
+            # manual axes check adds nothing.
+            check_vma=False,
         )(shares, iis, t_slr, t_cfg, n_t_eff, n_f_eff, resume_cost)
 
     return jax.jit(sweep, static_argnames=("repay_init",))
@@ -175,7 +178,6 @@ def _jitted_batch_resilient_sweep(n_shards: int):
             placement_sweep_batch_resilient_ref, static_argnames=("repay_init",)
         )
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("i",))
@@ -194,14 +196,14 @@ def _jitted_batch_resilient_sweep(n_shards: int):
         *,
         repay_init,
     ):
-        return shard_map(
+        return jax.shard_map(
             functools.partial(
                 placement_sweep_batch_resilient_ref, repay_init=repay_init
             ),
             mesh=mesh,
             in_specs=(P("i"),) * 9 + (P(),),
             out_specs=(P("i"), P("i"), P("i"), P("i")),
-            check_rep=False,
+            check_vma=False,
         )(
             shares,
             iis,
@@ -220,10 +222,13 @@ def _jitted_batch_resilient_sweep(n_shards: int):
 
 @register_backend("jax")
 class JaxPlacementBackend:
-    """``lax.while_loop`` sweep, float64 via scoped ``enable_x64``."""
+    """``lax.while_loop`` sweep, float64 via the scoped ``x64``."""
 
     name = "jax"
     async_dispatch = True
+
+    def __init__(self) -> None:
+        configure_compile_cache()
 
     @classmethod
     def available(cls) -> bool:
@@ -253,13 +258,11 @@ class JaxPlacementBackend:
         )
         if early is not None:
             return lambda: early
-        from jax.experimental import enable_x64
-
         B = shares.shape[0]
         Bp = _pad_rows(B)
         if Bp != B:
             shares = np.pad(shares, ((0, Bp - B), (0, 0)))
-        with enable_x64():
+        with x64():
             if opts.resilience:
                 t_slr_s, t_cfg_s = survivor_tables(
                     t_slr_arr, t_cfg_arr, opts.resilience
@@ -335,8 +338,6 @@ class JaxPlacementBackend:
             # the batch): the traced sweep cannot index zero-width tables,
             # but prepare_block's early paths answer every instance.
             return None
-        from jax.experimental import enable_x64
-
         Bp = _pad_pow2(B)
         Rp = _pad_rows(batch.shares.shape[1])
         shares = batch.shares
@@ -359,7 +360,7 @@ class JaxPlacementBackend:
         n_f_eff = np.pad(batch.n_f_eff, (0, pad_b)) if pad_b else batch.n_f_eff
 
         n_shards = resolve_shard(shard, Bp)
-        with enable_x64():
+        with x64():
             if opts.resilience:
                 if pad_b:
                     t_slr_s = np.pad(t_slr_s, ((0, pad_b), (0, 0)))

@@ -8,10 +8,16 @@ per call.  Off-TPU the kernel executes in Pallas interpret mode (correct
 but slow — useful for parity testing, not throughput; ``"auto"`` only
 selects this backend on a TPU host).
 
-Float64 comes from the same scoped ``enable_x64`` as the jax backend, so
-interpret-mode verdicts are bit-identical to the scalar oracle.  On TPU
-hardware float64 is unavailable; there the kernel lowers at float32 and
-bit-parity relaxes to float32 accuracy (see ``kernels/placement_step.py``).
+Off-TPU the kernel interprets at float64 under the same scoped ``x64``
+as the jax backend, so its verdicts are bit-identical to the scalar
+oracle.  Mosaic has no float64, so on a TPU the kernel lowers at float32
+(``jax_runtime.pallas_precision``).  Nothing in the kernel bounds how far
+a float32 verdict may stray from the oracle's near a capacity threshold.
+On a TPU v5 lite every plan of ``chip_smoke.py`` (paper Example 1, the
+425k-row deep instance at k=0 and k=1, 64 batched instances, a 20-event
+service trace) was identical to the numpy engine's and the scalar
+oracle's; ``resolve_engine("auto")`` picks this engine on a TPU only
+while that holds.
 
 Fleet-parallel batching: ``dispatch_blocks`` wraps the grid-extended
 kernel (:func:`repro.kernels.ops.placement_sweep_batch`) — the pallas
@@ -35,6 +41,7 @@ from .base import (
     survivor_batch_tables,
     survivor_tables,
 )
+from .jax_runtime import configure_compile_cache, pallas_precision
 
 __all__ = ["PallasPlacementBackend"]
 
@@ -48,6 +55,7 @@ class PallasPlacementBackend:
 
     def __init__(self, block_rows: int = 1024) -> None:
         self.block_rows = block_rows
+        configure_compile_cache()
 
     @classmethod
     def available(cls) -> bool:
@@ -77,11 +85,7 @@ class PallasPlacementBackend:
         )
         if early is not None:
             return lambda: early
-        import contextlib
-
-        from jax.experimental import enable_x64
-
-        from repro.kernels.ops import on_tpu, placement_sweep
+        from repro.kernels.ops import placement_sweep
 
         # Survivor tables are selected at float64 (the lexsort that picks
         # the worst-case adversary must match the other backends) before
@@ -89,19 +93,12 @@ class PallasPlacementBackend:
         surv = None
         if opts.resilience:
             surv = survivor_tables(t_slr_arr, t_cfg_arr, opts.resilience)
-        # TPUs have no float64: lower the kernel at float32 there (verdicts
-        # are float32-accurate, not bit-pinned); everywhere else the kernel
-        # interprets at float64 under scoped x64 and stays bit-identical.
-        if on_tpu():
-            precision_ctx = contextlib.nullcontext()
-            shares = shares.astype(np.float32)
-            iis = iis.astype(np.float32)
-            t_slr_arr = t_slr_arr.astype(np.float32)
-            t_cfg_arr = t_cfg_arr.astype(np.float32)
-            if surv is not None:
-                surv = tuple(a.astype(np.float32) for a in surv)
-        else:
-            precision_ctx = enable_x64()
+        dtype, precision_ctx = pallas_precision()
+        shares, iis, t_slr_arr, t_cfg_arr = (
+            a.astype(dtype, copy=False) for a in (shares, iis, t_slr_arr, t_cfg_arr)
+        )
+        if surv is not None:
+            surv = tuple(a.astype(dtype, copy=False) for a in surv)
         with precision_ctx:
             outs = placement_sweep(
                 shares,
@@ -177,35 +174,26 @@ class PallasPlacementBackend:
             # Zero-width task/device tables cannot flow through the kernel;
             # prepare_block's early paths answer every instance.
             return None
-        import contextlib
+        from repro.kernels.ops import placement_sweep_batch
 
-        from jax.experimental import enable_x64
-
-        from repro.kernels.ops import on_tpu, placement_sweep_batch
-
-        shares, iis = batch.shares, batch.iis
-        t_slr, t_cfg = batch.t_slr, batch.t_cfg
         surv = None
         if opts.resilience:
             # Per-instance worst-case survivor tables, selected at float64
             # before any TPU cast (see dispatch_block).
             surv = survivor_batch_tables(
-                t_slr, t_cfg, batch.n_f_eff, opts.resilience
+                batch.t_slr, batch.t_cfg, batch.n_f_eff, opts.resilience
             )
-        if on_tpu():
-            precision_ctx = contextlib.nullcontext()
-            shares = shares.astype(np.float32)
-            iis = iis.astype(np.float32)
-            t_slr = t_slr.astype(np.float32)
-            t_cfg = t_cfg.astype(np.float32)
-            if surv is not None:
-                surv = (
-                    surv[0].astype(np.float32),
-                    surv[1].astype(np.float32),
-                    surv[2],
-                )
-        else:
-            precision_ctx = enable_x64()
+        dtype, precision_ctx = pallas_precision()
+        shares, iis, t_slr, t_cfg = (
+            a.astype(dtype, copy=False)
+            for a in (batch.shares, batch.iis, batch.t_slr, batch.t_cfg)
+        )
+        if surv is not None:
+            surv = (
+                surv[0].astype(dtype, copy=False),
+                surv[1].astype(dtype, copy=False),
+                surv[2],
+            )
         with precision_ctx:
             outs = placement_sweep_batch(
                 shares,

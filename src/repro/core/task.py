@@ -12,7 +12,9 @@ Shares follow eq. 5:  ``shr_ij = td_i / (th_ij * p_i) * t_slr``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import operator
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -313,13 +315,17 @@ class TaskSetCombo:
     shares: tuple[float, ...]
     powers: tuple[float, ...]
 
+    # Both are left folds in task order, bit-identical to the enumerators'
+    # ``outer_sum`` rows that warm replans bound them against.  Python
+    # 3.12's ``sum`` of floats is compensated and can differ in the last
+    # place.
     @property
     def sum_shr(self) -> float:
-        return float(sum(self.shares))
+        return float(functools.reduce(operator.add, self.shares, 0.0))
 
     @property
     def total_power(self) -> float:
-        return float(sum(self.powers))
+        return float(functools.reduce(operator.add, self.powers, 0.0))
 
     def describe(self, tasks: Sequence[Task]) -> str:
         parts = []

@@ -2,25 +2,33 @@
 
 The scheduler's hot path advances a per-row simulation state (device
 cursor ``j``, task cursor ``k``, remaining capacity ``c``, carried share
-``tsd``) over a ``(B, n_t)`` shares block.  The jax backend expresses one
-step as ~15 gather/where ops XLA schedules independently; here the whole
-sweep is *one kernel*: a row tile of the block plus the (tiny) per-task
-and per-device tables live in VMEM, and an in-kernel ``fori_loop`` runs
+``tsd``) over a block of TFS rows.  The jax backend expresses one step as
+~15 gather/where ops XLA schedules independently; here the whole sweep is
+*one kernel*: a row tile of the block lives in VMEM, the (tiny) per-task
+and per-device tables live in SMEM, and an in-kernel ``fori_loop`` runs
 all ``n_t + n_f`` carry/split steps over that tile before it is written
-back — no intermediate HBM traffic, so blocks of ~10^6 rows sweep per
-call.
+back — no intermediate HBM traffic.
 
-Gathers (``iis[k]``, ``t_cfg[j]``, ``shares[row, k]``) are one-hot
-masked row reductions instead of dynamic-index loads: with the cursor
-clipped into range exactly one column survives the mask, so the sum
-reproduces the gathered float64 value bit-exactly while staying
-TPU-lowerable (no scatter/gather lowering).
+Layout: rows lie along the lanes.  A tile of ``bR`` rows is held as
+``(bR // 128, 128)`` arrays — every state vector is lane-dense — and the
+shares tile as ``(n_t, bR // 128, 128)``, one dense slab per task column.
+Gathers (``iis[k]``, ``t_cfg[j]``, ``shares[row, k]``) are select chains
+over the static task/device index: with the cursor clipped into range
+exactly one branch survives, so the gathered value is bit-exact in any
+float width and nothing lowers to a scatter/gather.
 
-Validated in interpret mode against ``ref.placement_sweep_ref`` (which
-is itself pinned bit-for-bit to the scalar Alg-2/Alg-3 oracle).  On
-non-TPU hosts the kernel runs in interpret mode (see ``ops.py``); on TPU
-float64 is unavailable, so bit-parity claims hold where the kernel is
-lowerable at float64 (interpret mode) and to float32 accuracy otherwise.
+The solo sweep is the instance-batched kernel at one instance: its
+effective counts are the full widths, so the same step arithmetic serves
+both entry points.
+
+Precision: in interpret mode (off-TPU) the kernel runs at float64 and is
+pinned bit-for-bit to ``ref.placement_sweep_ref``, itself pinned to the
+scalar Alg-2/Alg-3 oracle.  A TPU has no float64 in Mosaic, so there the
+backend lowers the kernel at float32
+(``placement_backends.jax_runtime.pallas_precision``).  Nothing bounds
+how far a float32 verdict may stray near a capacity threshold; on a TPU
+v5 lite the chip smoke (``chip_smoke.py``) found every plan identical to
+the numpy float64 engine's and the scalar oracle's.
 """
 
 from __future__ import annotations
@@ -29,75 +37,85 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import _PLACE_EPS
 
 __all__ = ["placement_sweep_pallas", "placement_sweep_batch_pallas"]
 
-
-def _onehot(cursor, width: int):
-    """(bB, 1) int cursor -> (bB, width) one-hot bool mask."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (cursor.shape[0], width), 1)
-    return cols == cursor
+LANES = 128
 
 
-def _select(mask, table_row):
-    """Masked row reduction: exact gather of one element per row.
-
-    ``mask`` is (bB, width) with exactly one True per row; ``table_row``
-    broadcasts (1, width) or (bB, width).  Summing a single surviving
-    element over zeros is bit-exact in any float width.
-    """
-    return jnp.sum(jnp.where(mask, table_row, 0.0), axis=1, keepdims=True)
+def _pick(idx, values):
+    """Exact gather ``values[idx]`` for an index already clipped in range."""
+    out = values[0]
+    for i in range(1, len(values)):
+        out = jnp.where(idx == i, values[i], out)
+    return out
 
 
 def _placement_sweep_kernel(
-    shares_ref,  # (bB, n_t)
-    iis_ref,  # (1, n_t)
-    slr_ref,  # (1, n_f)
-    cfg_ref,  # (1, n_f)
-    resume_ref,  # (1, 1) — t_capture + t_store (traced, no recompiles)
-    feas_ref,  # (bB, 1) int32 out
-    placed_ref,  # (bB, 1) int32 out
-    splits_ref,  # (bB, 1) int32 out
-    devused_ref,  # (bB, 1) int32 out
+    iis_ref,  # SMEM (B * n_t,)
+    slr_ref,  # SMEM (B * n_f,)
+    cfg_ref,  # SMEM (B * n_f,)
+    eff_ref,  # SMEM (B * 2,) int32 — [n_t_eff, n_f_eff] per instance
+    resume_ref,  # SMEM (1,) — t_capture + t_store (traced, no recompiles)
+    shares_ref,  # VMEM (1, n_t, S, 128) — one instance's row tile
+    feas_ref,  # VMEM (1, S, 128) int32 out
+    placed_ref,
+    splits_ref,
+    devused_ref,
     *,
-    n_steps: int,
+    n_t: int,
+    n_f: int,
     repay_init: bool,
 ):
-    shares = shares_ref[...]
-    iis_row = iis_ref[...]  # (1, n_t)
-    slr_row = slr_ref[...]  # (1, n_f)
-    cfg_row = cfg_ref[...]
-    resume_cost = resume_ref[0, 0]
-    bB, n_t = shares.shape
-    n_f = slr_row.shape[1]
-    dt = shares.dtype
+    """One grid cell: the full ``n_t + n_f``-step sweep of one row tile.
 
-    c0 = jnp.full((bB, 1), slr_row[0, 0], dtype=dt)
+    The grid is ``(B, Rp // bR)``: axis 0 walks instances (each cell reads
+    its own task/device tables and effective counts from SMEM), axis 1
+    walks row tiles.  Padded task columns / device slots beyond the
+    instance's effective counts are never read, so a live row replays the
+    unpadded sweep's add/sub chain exactly.
+    """
+    b = pl.program_id(0)
+    iis = [iis_ref[b * n_t + t] for t in range(n_t)]
+    slr = [slr_ref[b * n_f + f] for f in range(n_f)]
+    cfg = [cfg_ref[b * n_f + f] for f in range(n_f)]
+    n_t_eff = eff_ref[2 * b]
+    n_f_eff = eff_ref[2 * b + 1]
+    resume_cost = resume_ref[0]
+    shares = [shares_ref[0, t] for t in range(n_t)]
+    shape = shares[0].shape
+    dt = shares[0].dtype
+
+    # Mosaic lays an int splat constant out replicated, and a loop carry
+    # keeps the layout of its initial value; a zero loaded back from VMEM
+    # has the ordinary lane-dense layout the step's results take.
+    placed_ref[0] = jnp.zeros(shape, jnp.int32)
+    zeros_i = placed_ref[0]
     state = (
-        jnp.zeros((bB, 1), jnp.int32),  # j
-        jnp.zeros((bB, 1), jnp.int32),  # k
-        c0,  # c
-        jnp.zeros((bB, 1), dt),  # tsd
-        jnp.zeros((bB, 1), jnp.bool_),  # dead
-        jnp.zeros((bB, 1), jnp.int32),  # n_splits
-        jnp.zeros((bB, 1), jnp.int32),  # devices_used
+        zeros_i,  # j — device cursor
+        zeros_i,  # k — task cursor
+        jnp.full(shape, slr[0], dtype=dt),  # c — remaining capacity
+        jnp.zeros(shape, dt),  # tsd — carried share of task k
+        zeros_i,  # dead (0/1)
+        zeros_i,  # n_splits
+        zeros_i,  # devices_used
     )
 
     def step(_, state):
         j, k, c, tsd, dead, n_splits, devices_used = state
-        live = ~dead & (k < n_t)
+        live = (dead == 0) & (k < n_t_eff)
         kk = jnp.minimum(k, n_t - 1)
         jj = jnp.minimum(j, n_f - 1)
-        oh_k = _onehot(kk, n_t)
-        oh_j = _onehot(jj, n_f)
-        ii = _select(oh_k, iis_row)
-        tcfg = _select(oh_j, cfg_row)
+        ii = _pick(kk, iis)
+        tcfg = _pick(jj, cfg)
         carried = tsd > _PLACE_EPS
         extra = jnp.where(carried, ii if repay_init else resume_cost, 0.0)
-        rem = _select(oh_k, shares) - tsd
+        rem = _pick(kk, shares) - tsd
         avail = (c - tcfg) - extra
         can_start = (c > tcfg + ii + _PLACE_EPS) & (avail > _PLACE_EPS) & live
         split = can_start & (rem - avail > _PLACE_EPS)
@@ -107,30 +125,37 @@ def _placement_sweep_kernel(
             can_start, jnp.maximum(devices_used, jj + 1), devices_used
         )
         tsd = jnp.where(split, tsd + avail, tsd)
-        n_splits = n_splits + (split & ~carried)
+        n_splits = n_splits + (split & ~carried).astype(jnp.int32)
 
         c_after = avail - rem
         closure = fits & (c_after <= tcfg + ii + _PLACE_EPS)
         c = jnp.where(fits, c_after, c)
-        k = k + fits
+        k = k + fits.astype(jnp.int32)
         tsd = jnp.where(fits, 0.0, tsd)
 
         advance = (~can_start | split | closure) & live
-        j_next = j + advance
-        still_working = k < n_t
-        overflow = advance & (j_next >= n_f) & still_working
-        dead = dead | overflow
-        refill = advance & (j_next < n_f)
-        c = jnp.where(refill, _select(_onehot(jnp.minimum(j_next, n_f - 1), n_f), slr_row), c)
+        j_next = j + advance.astype(jnp.int32)
+        overflow = advance & (j_next >= n_f_eff) & (k < n_t_eff)
+        dead = dead | overflow.astype(jnp.int32)
+        refill = advance & (j_next < n_f_eff)
+        c = jnp.where(refill, _pick(jnp.minimum(j_next, n_f - 1), slr), c)
         return (j_next, k, c, tsd, dead, n_splits, devices_used)
 
-    j, k, c, tsd, dead, n_splits, devices_used = jax.lax.fori_loop(
-        0, n_steps, step, state
+    _, k, _, _, dead, n_splits, devices_used = jax.lax.fori_loop(
+        0, n_t + n_f, step, state
     )
-    feas_ref[...] = ((k >= n_t) & ~dead).astype(jnp.int32)
-    placed_ref[...] = k
-    splits_ref[...] = n_splits
-    devused_ref[...] = devices_used
+    feas_ref[0] = ((k >= n_t_eff) & (dead == 0)).astype(jnp.int32)
+    placed_ref[0] = k
+    splits_ref[0] = n_splits
+    devused_ref[0] = devices_used
+
+
+def _pow2(n: int, floor: int) -> int:
+    """Next power of two >= n, at least ``floor``."""
+    p = floor
+    while p < n:
+        p <<= 1
+    return p
 
 
 def placement_sweep_pallas(
@@ -147,121 +172,24 @@ def placement_sweep_pallas(
     """Fused block placement sweep; same contract as
     ``ref.placement_sweep_ref``.
 
-    Rows are tiled ``block_rows`` at a time through VMEM; the grid is the
-    row-tile count, and each grid cell runs the entire ``n_t + n_f``-step
-    simulation for its tile.  Degenerate ``n_t == 0`` / ``n_f == 0``
-    blocks are the caller's job (see ``placement_backends.base``).
-
-    Padding happens *outside* the jit boundary, to the next power of two
-    (>= 8): distinct input heights B collapse onto O(log B) compiled
-    specializations instead of one retrace per height.  Zero-share
-    padding rows trivially "place" and are sliced off.
+    The instance-batched kernel at one instance.  Degenerate
+    ``n_t == 0`` / ``n_f == 0`` blocks are the caller's job (see
+    ``placement_backends.base``).
     """
-    B = shares.shape[0]
-    Bp = 8
-    while Bp < B:
-        Bp <<= 1
-    if Bp != B:
-        shares = jnp.pad(shares, ((0, Bp - B), (0, 0)))
-    feas, placed, n_splits, devices_used = _placement_sweep_padded(
-        shares, iis, t_slr, t_cfg, resume_cost,
-        repay_init=repay_init, block_rows=block_rows, interpret=interpret,
+    n_t, n_f = shares.shape[1], t_slr.shape[0]
+    outs = placement_sweep_batch_pallas(
+        shares[None],
+        iis[None],
+        t_slr[None],
+        t_cfg[None],
+        np.full(1, n_t, np.int32),
+        np.full(1, n_f, np.int32),
+        resume_cost=resume_cost,
+        repay_init=repay_init,
+        block_rows=block_rows,
+        interpret=interpret,
     )
-    return feas[:B], placed[:B], n_splits[:B], devices_used[:B]
-
-
-def _placement_sweep_batch_kernel(
-    shares_ref,  # (1, bR, n_t) — one instance's row tile
-    iis_ref,  # (1, n_t) — this instance's task table
-    slr_ref,  # (1, n_f) — this instance's device capacities
-    cfg_ref,  # (1, n_f)
-    eff_ref,  # (1, 2) int32 — [n_t_eff, n_f_eff] for this instance
-    resume_ref,  # (1, 1)
-    feas_ref,  # (1, bR, 1) int32 out
-    placed_ref,  # (1, bR, 1) int32 out
-    splits_ref,  # (1, bR, 1) int32 out
-    devused_ref,  # (1, bR, 1) int32 out
-    *,
-    n_steps: int,
-    repay_init: bool,
-):
-    """Instance-axis twin of ``_placement_sweep_kernel``.
-
-    The grid is ``(B, Rp // bR)``: axis 0 walks instances (each grid cell
-    sees its own ``iis``/``t_slr``/``t_cfg`` tables and effective counts),
-    axis 1 walks row tiles within the instance's block.  The step
-    arithmetic is the single-instance kernel's with the static
-    ``n_t``/``n_f`` widths replaced by the *traced* effective counts —
-    padded columns/slots are never read, so live rows replay the exact
-    float64 chain and verdicts stay bit-identical per instance.
-    """
-    shares = shares_ref[0]  # (bR, n_t)
-    iis_row = iis_ref[...]  # (1, n_t)
-    slr_row = slr_ref[...]  # (1, n_f)
-    cfg_row = cfg_ref[...]
-    n_t_eff = eff_ref[0, 0]
-    n_f_eff = eff_ref[0, 1]
-    resume_cost = resume_ref[0, 0]
-    bB, n_t = shares.shape
-    n_f = slr_row.shape[1]
-    dt = shares.dtype
-
-    c0 = jnp.full((bB, 1), slr_row[0, 0], dtype=dt)
-    state = (
-        jnp.zeros((bB, 1), jnp.int32),  # j
-        jnp.zeros((bB, 1), jnp.int32),  # k
-        c0,  # c
-        jnp.zeros((bB, 1), dt),  # tsd
-        jnp.zeros((bB, 1), jnp.bool_),  # dead
-        jnp.zeros((bB, 1), jnp.int32),  # n_splits
-        jnp.zeros((bB, 1), jnp.int32),  # devices_used
-    )
-
-    def step(_, state):
-        j, k, c, tsd, dead, n_splits, devices_used = state
-        live = ~dead & (k < n_t_eff)
-        kk = jnp.minimum(k, n_t - 1)
-        jj = jnp.minimum(j, n_f - 1)
-        oh_k = _onehot(kk, n_t)
-        oh_j = _onehot(jj, n_f)
-        ii = _select(oh_k, iis_row)
-        tcfg = _select(oh_j, cfg_row)
-        carried = tsd > _PLACE_EPS
-        extra = jnp.where(carried, ii if repay_init else resume_cost, 0.0)
-        rem = _select(oh_k, shares) - tsd
-        avail = (c - tcfg) - extra
-        can_start = (c > tcfg + ii + _PLACE_EPS) & (avail > _PLACE_EPS) & live
-        split = can_start & (rem - avail > _PLACE_EPS)
-        fits = can_start & ~split
-
-        devices_used = jnp.where(
-            can_start, jnp.maximum(devices_used, jj + 1), devices_used
-        )
-        tsd = jnp.where(split, tsd + avail, tsd)
-        n_splits = n_splits + (split & ~carried)
-
-        c_after = avail - rem
-        closure = fits & (c_after <= tcfg + ii + _PLACE_EPS)
-        c = jnp.where(fits, c_after, c)
-        k = k + fits
-        tsd = jnp.where(fits, 0.0, tsd)
-
-        advance = (~can_start | split | closure) & live
-        j_next = j + advance
-        still_working = k < n_t_eff
-        overflow = advance & (j_next >= n_f_eff) & still_working
-        dead = dead | overflow
-        refill = advance & (j_next < n_f_eff)
-        c = jnp.where(refill, _select(_onehot(jnp.minimum(j_next, n_f - 1), n_f), slr_row), c)
-        return (j_next, k, c, tsd, dead, n_splits, devices_used)
-
-    j, k, c, tsd, dead, n_splits, devices_used = jax.lax.fori_loop(
-        0, n_steps, step, state
-    )
-    feas_ref[0] = ((k >= n_t_eff) & ~dead).astype(jnp.int32)
-    placed_ref[0] = k
-    splits_ref[0] = n_splits
-    devused_ref[0] = devices_used
+    return tuple(o[0] for o in outs)
 
 
 def placement_sweep_batch_pallas(
@@ -280,34 +208,36 @@ def placement_sweep_batch_pallas(
     """Fleet-parallel fused sweep; same contract as
     ``ref.placement_sweep_batch_ref``.
 
-    One ``pallas_call`` sweeps every instance's block: the grid gains a
-    leading instance axis, each cell streaming one ``(block_rows, n_t)``
-    row tile of one instance through VMEM together with that instance's
-    per-task/per-device tables.  Rows are padded to the next power of two
-    (>= 8) outside the jit boundary — distinct (B, R) batch shapes
-    collapse onto O(log R) compiled specializations per (B, n_t, n_f)
-    topology.  Padded rows and all-padding instances (``n_t_eff == 0``)
-    trivially "place" and are the caller's to slice off.
+    One ``pallas_call`` sweeps every instance's block: the grid's leading
+    axis walks instances, each cell streaming one ``block_rows`` row tile
+    of one instance through VMEM.  Instances and rows are padded to the
+    next power of two (rows to >= 128) outside the jit boundary, so
+    distinct (B, R) batch shapes — a batched walk's live instance count
+    shrinks round by round — collapse onto O(log B · log R) compiled
+    specializations per (n_t, n_f) topology.  Padded instances carry
+    ``n_t_eff == 0`` and, like padded rows, trivially "place"; both are
+    sliced off here.
     """
     B, R, n_t = shares.shape
-    Rp = 8
-    while Rp < R:
-        Rp <<= 1
-    if Rp != R:
-        shares = jnp.pad(shares, ((0, 0), (0, Rp - R), (0, 0)))
-    eff = jnp.stack(
-        [jnp.asarray(n_t_eff, jnp.int32), jnp.asarray(n_f_eff, jnp.int32)], axis=1
-    )  # (B, 2)
+    if block_rows < LANES or block_rows & (block_rows - 1):
+        raise ValueError(
+            f"block_rows={block_rows} must be a power of two >= {LANES}"
+        )
+    Bp, Rp = _pow2(B, 1), _pow2(R, LANES)
+    # Pad where the arrays live: host arrays from the backends stay on the
+    # host, so no eager device op compiles per distinct (B, R).
+    xp = np if isinstance(shares, np.ndarray) else jnp
+    shares = xp.pad(shares, ((0, Bp - B), (0, Rp - R), (0, 0)))
+    iis, t_slr, t_cfg = (xp.pad(a, ((0, Bp - B), (0, 0))) for a in (iis, t_slr, t_cfg))
+    eff = xp.pad(
+        xp.stack([xp.asarray(n_t_eff), xp.asarray(n_f_eff)], axis=1).astype(xp.int32),
+        ((0, Bp - B), (0, 0)),
+    )
     feas, placed, n_splits, devices_used = _placement_sweep_batch_padded(
         shares, iis, t_slr, t_cfg, eff, resume_cost,
         repay_init=repay_init, block_rows=block_rows, interpret=interpret,
     )
-    return (
-        feas[:, :R],
-        placed[:, :R],
-        n_splits[:, :R],
-        devices_used[:, :R],
-    )
+    return tuple(o[:B, :R] for o in (feas, placed, n_splits, devices_used))
 
 
 @functools.partial(
@@ -315,7 +245,7 @@ def placement_sweep_batch_pallas(
     static_argnames=("repay_init", "block_rows", "interpret"),
 )
 def _placement_sweep_batch_padded(
-    shares: jax.Array,  # (B, Rp, n_t) — Rp a power of two >= 8
+    shares: jax.Array,  # (B, Rp, n_t) — Rp a power of two >= 128
     iis: jax.Array,
     t_slr: jax.Array,
     t_cfg: jax.Array,
@@ -329,95 +259,36 @@ def _placement_sweep_batch_padded(
     B, Rp, n_t = shares.shape
     n_f = t_slr.shape[1]
     dt = shares.dtype
+    # Rp and bR are both powers of two >= 128, so tiles divide exactly.
     bR = min(block_rows, Rp)
-    if Rp % bR:
-        raise ValueError(f"block_rows={block_rows} must divide padded R={Rp}")
+    S, Sp = bR // LANES, Rp // LANES
+    # Rows onto lanes: (B, Rp, n_t) -> (B, n_t, Rp // 128, 128).
+    shares_t = jnp.swapaxes(shares, 1, 2).reshape(B, n_t, Sp, LANES)
 
     kernel = functools.partial(
-        _placement_sweep_batch_kernel,
-        n_steps=n_t + n_f,
-        repay_init=repay_init,
+        _placement_sweep_kernel, n_t=n_t, n_f=n_f, repay_init=repay_init
     )
-    out_shape = [jax.ShapeDtypeStruct((B, Rp, 1), jnp.int32)] * 4
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out_spec = pl.BlockSpec((1, S, LANES), lambda b, r: (b, r, 0))
     feas, placed, n_splits, devices_used = pl.pallas_call(
         kernel,
-        grid=(B, Rp // bR),
-        in_specs=[
-            pl.BlockSpec((1, bR, n_t), lambda b, r: (b, r, 0)),
-            pl.BlockSpec((1, n_t), lambda b, r: (b, 0)),
-            pl.BlockSpec((1, n_f), lambda b, r: (b, 0)),
-            pl.BlockSpec((1, n_f), lambda b, r: (b, 0)),
-            pl.BlockSpec((1, 2), lambda b, r: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, r: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, bR, 1), lambda b, r: (b, r, 0))] * 4,
-        out_shape=out_shape,
+        grid=(B, Sp // S),
+        in_specs=[smem] * 5
+        + [pl.BlockSpec((1, n_t, S, LANES), lambda b, r: (b, 0, r, 0))],
+        out_specs=[out_spec] * 4,
+        out_shape=[jax.ShapeDtypeStruct((B, Sp, LANES), jnp.int32)] * 4,
         interpret=interpret,
     )(
-        shares,
-        iis.astype(dt),
-        t_slr.astype(dt),
-        t_cfg.astype(dt),
-        eff,
-        jnp.asarray(resume_cost, dtype=dt).reshape(1, 1),
+        iis.astype(dt).reshape(-1),
+        t_slr.astype(dt).reshape(-1),
+        t_cfg.astype(dt).reshape(-1),
+        eff.reshape(-1),
+        jnp.asarray(resume_cost, dtype=dt).reshape(1),
+        shares_t,
     )
     return (
-        feas[..., 0].astype(bool),
-        placed[..., 0],
-        n_splits[..., 0],
-        devices_used[..., 0],
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("repay_init", "block_rows", "interpret"),
-)
-def _placement_sweep_padded(
-    shares: jax.Array,  # (Bp, n_t) — Bp a power of two >= 8
-    iis: jax.Array,
-    t_slr: jax.Array,
-    t_cfg: jax.Array,
-    resume_cost,
-    *,
-    repay_init: bool,
-    block_rows: int,
-    interpret: bool,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    Bp, n_t = shares.shape
-    n_f = t_slr.shape[0]
-    dt = shares.dtype
-    # Both Bp and the default block_rows are powers of two, so the tile
-    # height always divides the padded height exactly.
-    bB = min(block_rows, Bp)
-    if Bp % bB:
-        raise ValueError(f"block_rows={block_rows} must divide padded B={Bp}")
-
-    kernel = functools.partial(
-        _placement_sweep_kernel,
-        n_steps=n_t + n_f,
-        repay_init=repay_init,
-    )
-    out_shape = [jax.ShapeDtypeStruct((Bp, 1), jnp.int32)] * 4
-    feas, placed, n_splits, devices_used = pl.pallas_call(
-        kernel,
-        grid=(Bp // bB,),
-        in_specs=[
-            pl.BlockSpec((bB, n_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_t), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_f), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_f), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((bB, 1), lambda i: (i, 0))] * 4,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(shares, iis.reshape(1, n_t).astype(dt), t_slr.reshape(1, n_f).astype(dt),
-      t_cfg.reshape(1, n_f).astype(dt),
-      jnp.asarray(resume_cost, dtype=dt).reshape(1, 1))
-    return (
-        feas[:, 0].astype(bool),
-        placed[:, 0],
-        n_splits[:, 0],
-        devices_used[:, 0],
+        feas.reshape(B, Rp).astype(bool),
+        placed.reshape(B, Rp),
+        n_splits.reshape(B, Rp),
+        devices_used.reshape(B, Rp),
     )

@@ -21,6 +21,12 @@ SINGLE_POD = ((16, 16), ("data", "model"))
 MULTI_POD = ((2, 16, 16), ("pod", "data", "model"))
 
 
+def _auto(axes: tuple[str, ...]) -> tuple:
+    """Auto axis types: the substrate shards with ``with_sharding_constraint``,
+    which ``jax.make_mesh``'s default Explicit axes refuse."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape, axes = MULTI_POD if multi_pod else SINGLE_POD
     n = 1
@@ -33,7 +39,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes), devices=devices[:n])
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
@@ -41,4 +47,6 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mes
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(
+        shape, axes, axis_types=_auto(axes), devices=jax.devices()[:n]
+    )

@@ -36,6 +36,7 @@ from repro.core import (
     FleetSpec,
     PADPSFRScheduler,
     Task,
+    TaskSetCombo,
     TaskVariant,
     WalkStats,
     block_ramp,
@@ -386,6 +387,22 @@ class TestOuterSumRegression:
             for v in vecs:
                 acc = (acc[:, None] + v[None, :]).reshape(-1)
             assert (got == acc).all()  # bitwise: same fold order
+
+    def test_combo_folds_match_outer_sum_rows(self):
+        """``TaskSetCombo`` totals are the enumerators' fold bit for bit.
+
+        Warm replans bound numpy-folded candidate rows by a combo's
+        ``total_power``; Python 3.12's compensated ``sum`` differs from
+        the left fold on about a quarter of these rows, which once dropped
+        the incumbent row from a warm arrival's candidates."""
+        rng = np.random.default_rng(0)
+        vecs = [rng.uniform(3.0, 9.0, 4) for _ in range(5)]
+        folded = outer_sum(vecs)
+        for flat, idx in enumerate(np.ndindex(*(len(v) for v in vecs))):
+            vals = tuple(float(v[i]) for v, i in zip(vecs, idx, strict=True))
+            combo = TaskSetCombo(idx, vals, vals)
+            assert combo.total_power == folded[flat]
+            assert combo.sum_shr == folded[flat]
 
     def test_empty_input(self):
         assert (outer_sum([]) == np.zeros(1)).all()

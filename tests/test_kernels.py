@@ -211,16 +211,15 @@ def _placement_block(B=257, n_t=6, n_f=5, seed=0):
     return shares, iis, t_slr, t_cfg
 
 
-@pytest.mark.parametrize("block_rows", [64, 1024], ids=["tiled", "one-tile"])
+@pytest.mark.parametrize("block_rows", [128, 1024], ids=["tiled", "one-tile"])
 @pytest.mark.parametrize("repay_init", [True, False], ids=["padpsfr", "preemptive"])
 def test_placement_sweep_pallas_matches_ref(block_rows, repay_init):
-    from jax.experimental import enable_x64
-
+    from repro.core.placement_backends.jax_runtime import x64
     from repro.kernels.placement_step import placement_sweep_pallas
 
     shares, iis, t_slr, t_cfg = _placement_block()
     resume = 0.0 if repay_init else 9.5
-    with enable_x64():
+    with x64():
         want = ref.placement_sweep_ref(
             jnp.asarray(shares), jnp.asarray(iis), jnp.asarray(t_slr),
             jnp.asarray(t_cfg), jnp.float64(resume), repay_init=repay_init,
@@ -238,13 +237,12 @@ def test_placement_sweep_pallas_matches_ref(block_rows, repay_init):
 
 def test_placement_sweep_ref_matches_numpy_backend():
     """The jnp reference is pinned to the core numpy engine bit-for-bit."""
-    from jax.experimental import enable_x64
-
     from repro.core.placement_backends import get_backend
+    from repro.core.placement_backends.jax_runtime import x64
 
     shares, iis, t_slr, t_cfg = _placement_block(B=123, seed=3)
     bn = get_backend("numpy").place_block(shares, iis, t_slr, t_cfg)
-    with enable_x64():
+    with x64():
         feas, placed, n_splits, dev = ref.placement_sweep_ref(
             jnp.asarray(shares), jnp.asarray(iis), jnp.asarray(t_slr),
             jnp.asarray(t_cfg), jnp.float64(0.0),

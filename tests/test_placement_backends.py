@@ -9,12 +9,14 @@ to the scalar Alg-2/Alg-3 oracle bit-for-bit.  This file covers:
   ``count_all_rejects`` — backend-independent by construction;
 * jax-gated cross-backend parity (jit'd ``lax.while_loop`` sweep and the
   fused Pallas kernel) on the paper's Figs 2-4 examples and >= 100
-  randomized heterogeneous fleets under scoped ``enable_x64``.
+  randomized heterogeneous fleets under the engines' scoped x64
+  (``placement_backends.jax_runtime.x64``).
 
 The randomized-instance harness is shared with
 ``tests/test_placement_batched.py``.
 """
 
+from pathlib import Path
 from types import SimpleNamespace
 
 import numpy as np
@@ -523,3 +525,23 @@ def test_eager_backend_dispatch_matches_place():
             shares, iis, fleet.t_slr_arr, fleet.t_cfg_arr
         )()
         _assert_blocks_identical(eager, resolved, f"{name} dispatch parity")
+
+
+@needs_jax
+@pytest.mark.parametrize("preset", [None, "elsewhere"], ids=["checkout", "env"])
+def test_compile_cache_dir(preset, tmp_path):
+    """A preset cache directory (what ``JAX_COMPILATION_CACHE_DIR`` sets)
+    is kept; without one the cache goes to ``.jax_cache/`` in the checkout."""
+    import jax
+
+    from repro.core.placement_backends import jax_runtime
+
+    was = jax.config.jax_compilation_cache_dir
+    want = str(tmp_path / preset) if preset else str(jax_runtime.CACHE_DIR)
+    try:
+        jax.config.update("jax_compilation_cache_dir", want if preset else None)
+        assert jax_runtime.configure_compile_cache.__wrapped__() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert jax_runtime.CACHE_DIR.parent == Path(__file__).resolve().parents[1]
