@@ -762,6 +762,27 @@ def _churn_task(rng, name: str) -> Task:
     )
 
 
+def _short_churn_trace(svc, n_events: int = 20, seed: int = 11):
+    """Drive ``svc`` through a short seeded trace, yielding each event's
+    telemetry once the event has been applied.
+
+    Arrivals and exits, with one device failure (the 10th event) and one
+    recovery (the 16th): every kind of event ``SchedulerService`` takes.
+    """
+    rng = np.random.default_rng(seed)
+    for i in range(n_events):
+        n_alive = len(svc.tasks)
+        roll = float(rng.random())
+        if i == 9:
+            yield svc.fail_device()
+        elif i == 15:
+            yield svc.recover_device()
+        elif (roll < 0.6 and n_alive < 6) or n_alive < 2:
+            yield svc.submit(_churn_task(rng, f"c{i}"))
+        else:
+            yield svc.remove(svc.tasks[int(rng.integers(0, n_alive))].name)
+
+
 def _eps_task(t_slr: float, name: str = "eps") -> Task:
     """One-variant task with negligible share and power.
 

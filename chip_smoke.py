@@ -111,29 +111,16 @@ def _many(engine, shard=None, n=N_BATCH):
 
 def _service_trace(engine):
     """Replay the seeded trace; returns one snapshot per solved event."""
-    import numpy as np
-
-    from benchmarks.scheduler_scale import _churn_task
+    from benchmarks.scheduler_scale import _short_churn_trace
     from repro.core import FleetSpec
     from repro.service import SchedulerService
 
-    rng = np.random.default_rng(11)
     svc = SchedulerService(FleetSpec(n_f=4, t_slr=35.0, t_cfg=1.0), engine=engine)
-    snaps = []
-    for i in range(N_EVENTS):
-        n_alive = len(svc.tasks)
-        roll = float(rng.random())
-        if i == 9:
-            tel = svc.fail_device()
-        elif i == 15:
-            tel = svc.recover_device()
-        elif (roll < 0.6 and n_alive < 6) or n_alive < 2:
-            tel = svc.submit(_churn_task(rng, f"c{i}"))
-        else:
-            tel = svc.remove(svc.tasks[int(rng.integers(0, n_alive))].name)
-        if svc.tasks:
-            snaps.append((tel, svc.tasks, svc.fleet, svc.plan))
-    return snaps
+    return [
+        (tel, svc.tasks, svc.fleet, svc.plan)
+        for tel in _short_churn_trace(svc, N_EVENTS)
+        if svc.tasks
+    ]
 
 
 # -- references --------------------------------------------------------------

@@ -396,21 +396,9 @@ def test_warm_arrival_keeps_incumbent_row_after_rerecord():
     total power came from Python's compensated ``sum`` while the warm
     candidates carried the enumerators' left fold, one ulp apart.  Every
     event's live plan must equal a cold solve of what survived it."""
-    np = pytest.importorskip("numpy")
-    from benchmarks.scheduler_scale import _churn_task
+    from benchmarks.scheduler_scale import _short_churn_trace
 
-    rng = np.random.default_rng(11)
     svc = SchedulerService(FleetSpec(n_f=4, t_slr=35.0, t_cfg=1.0))
-    for i in range(20):
-        n_alive = len(svc.tasks)
-        roll = float(rng.random())
-        if i == 9:
-            svc.fail_device()
-        elif i == 15:
-            svc.recover_device()
-        elif (roll < 0.6 and n_alive < 6) or n_alive < 2:
-            svc.submit(_churn_task(rng, f"c{i}"))
-        else:
-            svc.remove(svc.tasks[int(rng.integers(0, n_alive))].name)
+    for _ in _short_churn_trace(svc):
         _assert_matches_cold(svc)
     assert svc.rerecord_count >= 1
