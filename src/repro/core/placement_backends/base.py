@@ -170,6 +170,7 @@ from typing import Protocol, runtime_checkable
 
 import numpy as np
 
+from ... import trace
 from ..task import worst_case_survivor_indices
 
 __all__ = [
@@ -183,6 +184,7 @@ __all__ = [
     "backend_names",
     "available_backends",
     "prepare_block",
+    "fetch",
     "place_instance_blocks",
     "dispatch_instance_blocks",
     "survivor_tables",
@@ -557,6 +559,14 @@ _LAZY_BACKENDS: dict[str, str] = {
 
 # Historical engine names kept working across the PR-1 -> PR-2 refactor.
 _ALIASES: dict[str, str] = {"batched": "numpy"}
+
+
+def fetch(arrays) -> list[np.ndarray]:
+    """Device verdict arrays as host numpy arrays (the blocking sync),
+    counted as ``d2h_bytes`` of the walk in progress (:mod:`repro.trace`)."""
+    out = [np.asarray(a) for a in arrays]
+    trace.count("d2h_bytes", sum(a.nbytes for a in out))
+    return out
 
 
 def register_backend(name: str):

@@ -47,10 +47,12 @@ import functools
 
 import numpy as np
 
+from ... import trace
 from .base import (
     BatchPlacement,
     InstanceBatch,
     PlacementOptions,
+    fetch,
     prepare_block,
     register_backend,
     survivor_batch_tables,
@@ -253,42 +255,28 @@ class JaxPlacementBackend:
         one's execution (see the ``dispatch_block`` contract in
         ``base.py``).
         """
-        shares, iis, t_slr_arr, t_cfg_arr, opts, early = prepare_block(
-            shares, iis, t_slr, t_cfg, opts
-        )
-        if early is not None:
-            return lambda: early
-        B = shares.shape[0]
-        Bp = _pad_rows(B)
-        if Bp != B:
-            shares = np.pad(shares, ((0, Bp - B), (0, 0)))
-        with x64():
+        with trace.span("sched.prepare", "prepare_us"):
+            shares, iis, t_slr_arr, t_cfg_arr, opts, early = prepare_block(
+                shares, iis, t_slr, t_cfg, opts
+            )
+            if early is not None:
+                return lambda: early
+            B = shares.shape[0]
+            Bp = _pad_rows(B)
+            if Bp != B:
+                shares = np.pad(shares, ((0, Bp - B), (0, 0)))
+            tables = (iis, t_slr_arr, t_cfg_arr)
             if opts.resilience:
-                t_slr_s, t_cfg_s = survivor_tables(
-                    t_slr_arr, t_cfg_arr, opts.resilience
-                )
-                outs = _jitted_resilient_sweep()(
-                    shares,
-                    iis,
-                    t_slr_arr,
-                    t_cfg_arr,
-                    t_slr_s,
-                    t_cfg_s,
-                    np.float64(opts.resume_cost),
-                    repay_init=opts.repay_init,
-                )
-            else:
-                outs = _jitted_sweep()(
-                    shares,
-                    iis,
-                    t_slr_arr,
-                    t_cfg_arr,
-                    np.float64(opts.resume_cost),
-                    repay_init=opts.repay_init,
-                )
+                tables += survivor_tables(t_slr_arr, t_cfg_arr, opts.resilience)
+        trace.note(padded_rows=Bp)
+        with x64(), trace.launch((shares, *tables)):
+            sweep = _jitted_resilient_sweep() if opts.resilience else _jitted_sweep()
+            outs = sweep(
+                shares, *tables, np.float64(opts.resume_cost), repay_init=opts.repay_init
+            )
 
         def resolve() -> BatchPlacement:
-            out = [np.asarray(a)[:B] for a in outs]
+            out = [a[:B] for a in fetch(outs)]
             return BatchPlacement(
                 feasible=out[0].astype(bool),
                 placed_tasks=out[1].astype(np.int64),
@@ -338,60 +326,46 @@ class JaxPlacementBackend:
             # the batch): the traced sweep cannot index zero-width tables,
             # but prepare_block's early paths answer every instance.
             return None
-        Bp = _pad_pow2(B)
-        Rp = _pad_rows(batch.shares.shape[1])
-        shares = batch.shares
-        pad_b, pad_r = Bp - B, Rp - shares.shape[1]
-        if opts.resilience:
-            # Survivor tables are computed per live instance before padding
-            # (padded instances keep n_f_eff_s == 0, matching their
-            # n_t_eff == 0 no-op status).
-            t_slr_s, t_cfg_s, n_f_eff_s = survivor_batch_tables(
-                batch.t_slr, batch.t_cfg, batch.n_f_eff, opts.resilience
-            )
-        if pad_b or pad_r:
-            # Padded instances carry n_t_eff == 0 (all-feasible no-ops);
-            # padded rows are garbage-swept and trimmed by the resolver.
-            shares = np.pad(shares, ((0, pad_b), (0, pad_r), (0, 0)))
-        iis = np.pad(batch.iis, ((0, pad_b), (0, 0))) if pad_b else batch.iis
-        t_slr = np.pad(batch.t_slr, ((0, pad_b), (0, 0))) if pad_b else batch.t_slr
-        t_cfg = np.pad(batch.t_cfg, ((0, pad_b), (0, 0))) if pad_b else batch.t_cfg
-        n_t_eff = np.pad(batch.n_t_eff, (0, pad_b)) if pad_b else batch.n_t_eff
-        n_f_eff = np.pad(batch.n_f_eff, (0, pad_b)) if pad_b else batch.n_f_eff
+        with trace.span("sched.prepare", "prepare_us"):
+            Bp = _pad_pow2(B)
+            Rp = _pad_rows(batch.shares.shape[1])
+            shares = batch.shares
+            pad_b, pad_r = Bp - B, Rp - shares.shape[1]
+            if opts.resilience:
+                # Survivor tables are computed per live instance before padding
+                # (padded instances keep n_f_eff_s == 0, matching their
+                # n_t_eff == 0 no-op status).
+                t_slr_s, t_cfg_s, n_f_eff_s = survivor_batch_tables(
+                    batch.t_slr, batch.t_cfg, batch.n_f_eff, opts.resilience
+                )
+            if pad_b or pad_r:
+                # Padded instances carry n_t_eff == 0 (all-feasible no-ops);
+                # padded rows are garbage-swept and trimmed by the resolver.
+                shares = np.pad(shares, ((0, pad_b), (0, pad_r), (0, 0)))
+            iis = np.pad(batch.iis, ((0, pad_b), (0, 0))) if pad_b else batch.iis
+            t_slr = np.pad(batch.t_slr, ((0, pad_b), (0, 0))) if pad_b else batch.t_slr
+            t_cfg = np.pad(batch.t_cfg, ((0, pad_b), (0, 0))) if pad_b else batch.t_cfg
+            n_t_eff = np.pad(batch.n_t_eff, (0, pad_b)) if pad_b else batch.n_t_eff
+            n_f_eff = np.pad(batch.n_f_eff, (0, pad_b)) if pad_b else batch.n_f_eff
 
-        n_shards = resolve_shard(shard, Bp)
-        with x64():
+            n_shards = resolve_shard(shard, Bp)
+            args = (shares, iis, t_slr, t_cfg, n_t_eff, n_f_eff)
             if opts.resilience:
                 if pad_b:
                     t_slr_s = np.pad(t_slr_s, ((0, pad_b), (0, 0)))
                     t_cfg_s = np.pad(t_cfg_s, ((0, pad_b), (0, 0)))
                     n_f_eff_s = np.pad(n_f_eff_s, (0, pad_b))
-                outs = _jitted_batch_resilient_sweep(n_shards)(
-                    shares,
-                    iis,
-                    t_slr,
-                    t_cfg,
-                    n_t_eff,
-                    n_f_eff,
-                    t_slr_s,
-                    t_cfg_s,
-                    n_f_eff_s,
-                    np.float64(opts.resume_cost),
-                    repay_init=opts.repay_init,
-                )
-            else:
-                outs = _jitted_batch_sweep(n_shards)(
-                    shares,
-                    iis,
-                    t_slr,
-                    t_cfg,
-                    n_t_eff,
-                    n_f_eff,
-                    np.float64(opts.resume_cost),
-                    repay_init=opts.repay_init,
-                )
+                args += (t_slr_s, t_cfg_s, n_f_eff_s)
+        trace.note(padded_rows=Rp)
+        with x64(), trace.launch(args):
+            sweep = (
+                _jitted_batch_resilient_sweep(n_shards)
+                if opts.resilience
+                else _jitted_batch_sweep(n_shards)
+            )
+            outs = sweep(*args, np.float64(opts.resume_cost), repay_init=opts.repay_init)
 
-        return lambda: tuple(np.asarray(a) for a in outs)
+        return lambda: tuple(fetch(outs))
 
     def dispatch_blocks(
         self,

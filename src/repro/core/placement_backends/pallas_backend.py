@@ -31,10 +31,12 @@ from __future__ import annotations
 
 import numpy as np
 
+from ... import trace
 from .base import (
     BatchPlacement,
     InstanceBatch,
     PlacementOptions,
+    fetch,
     place_instance_blocks,
     prepare_block,
     register_backend,
@@ -80,25 +82,26 @@ class PallasPlacementBackend:
         block's enumeration with this sweep; in interpret mode execution
         is eager and the resolver just repackages (see ``base.py``).
         """
-        shares, iis, t_slr_arr, t_cfg_arr, opts, early = prepare_block(
-            shares, iis, t_slr, t_cfg, opts
-        )
-        if early is not None:
-            return lambda: early
-        from repro.kernels.ops import placement_sweep
+        with trace.span("sched.prepare", "prepare_us"):
+            shares, iis, t_slr_arr, t_cfg_arr, opts, early = prepare_block(
+                shares, iis, t_slr, t_cfg, opts
+            )
+            if early is not None:
+                return lambda: early
+            from repro.kernels.ops import placement_sweep
 
-        # Survivor tables are selected at float64 (the lexsort that picks
-        # the worst-case adversary must match the other backends) before
-        # any TPU float32 cast.
-        surv = None
-        if opts.resilience:
-            surv = survivor_tables(t_slr_arr, t_cfg_arr, opts.resilience)
-        dtype, precision_ctx = pallas_precision()
-        shares, iis, t_slr_arr, t_cfg_arr = (
-            a.astype(dtype, copy=False) for a in (shares, iis, t_slr_arr, t_cfg_arr)
-        )
-        if surv is not None:
-            surv = tuple(a.astype(dtype, copy=False) for a in surv)
+            # Survivor tables are selected at float64 (the lexsort that picks
+            # the worst-case adversary must match the other backends) before
+            # any TPU float32 cast.
+            surv = None
+            if opts.resilience:
+                surv = survivor_tables(t_slr_arr, t_cfg_arr, opts.resilience)
+            dtype, precision_ctx = pallas_precision()
+            shares, iis, t_slr_arr, t_cfg_arr = (
+                a.astype(dtype, copy=False) for a in (shares, iis, t_slr_arr, t_cfg_arr)
+            )
+            if surv is not None:
+                surv = tuple(a.astype(dtype, copy=False) for a in surv)
         with precision_ctx:
             outs = placement_sweep(
                 shares,
@@ -125,10 +128,10 @@ class PallasPlacementBackend:
                 )
 
         def resolve() -> BatchPlacement:
-            out = [np.asarray(a) for a in outs]
+            out = fetch(outs)
             feasible = out[0].astype(bool)
             if outs_s is not None:
-                feasible = feasible & np.asarray(outs_s[0]).astype(bool)
+                feasible = feasible & fetch(outs_s[:1])[0].astype(bool)
             return BatchPlacement(
                 feasible=feasible,
                 placed_tasks=out[1].astype(np.int64),
@@ -176,24 +179,25 @@ class PallasPlacementBackend:
             return None
         from repro.kernels.ops import placement_sweep_batch
 
-        surv = None
-        if opts.resilience:
-            # Per-instance worst-case survivor tables, selected at float64
-            # before any TPU cast (see dispatch_block).
-            surv = survivor_batch_tables(
-                batch.t_slr, batch.t_cfg, batch.n_f_eff, opts.resilience
+        with trace.span("sched.prepare", "prepare_us"):
+            surv = None
+            if opts.resilience:
+                # Per-instance worst-case survivor tables, selected at float64
+                # before any TPU cast (see dispatch_block).
+                surv = survivor_batch_tables(
+                    batch.t_slr, batch.t_cfg, batch.n_f_eff, opts.resilience
+                )
+            dtype, precision_ctx = pallas_precision()
+            shares, iis, t_slr, t_cfg = (
+                a.astype(dtype, copy=False)
+                for a in (batch.shares, batch.iis, batch.t_slr, batch.t_cfg)
             )
-        dtype, precision_ctx = pallas_precision()
-        shares, iis, t_slr, t_cfg = (
-            a.astype(dtype, copy=False)
-            for a in (batch.shares, batch.iis, batch.t_slr, batch.t_cfg)
-        )
-        if surv is not None:
-            surv = (
-                surv[0].astype(dtype, copy=False),
-                surv[1].astype(dtype, copy=False),
-                surv[2],
-            )
+            if surv is not None:
+                surv = (
+                    surv[0].astype(dtype, copy=False),
+                    surv[1].astype(dtype, copy=False),
+                    surv[2],
+                )
         with precision_ctx:
             outs = placement_sweep_batch(
                 shares,
@@ -221,9 +225,9 @@ class PallasPlacementBackend:
                 )
 
         def resolve_raw():
-            feas, placed, n_splits, devices_used = (np.asarray(a) for a in outs)
+            feas, placed, n_splits, devices_used = fetch(outs)
             if outs_s is not None:
-                feas = feas.astype(bool) & np.asarray(outs_s[0]).astype(bool)
+                feas = feas.astype(bool) & fetch(outs_s[:1])[0].astype(bool)
             return feas, placed, n_splits, devices_used
 
         return resolve_raw

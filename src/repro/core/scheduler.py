@@ -29,11 +29,11 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
-import time
 from typing import Iterable, Iterator, Sequence
 
 import numpy as np
 
+from .. import trace
 from .feasibility import (
     FeasibilityResult,
     iter_feasible_pruned,
@@ -95,6 +95,19 @@ class WalkStats:
     ``sync_us`` time waiting for verdicts to come back, and
     ``materialize_us`` the winning row's scalar plan.  ``block_sizes``
     records the adaptive ramp actually dispatched.
+
+    Parts of those, from the spans of :mod:`repro.trace`: ``search_us``
+    is the eq-7 search of the entry call (outside the walk),
+    ``sort_us`` and ``gather_us`` the TFS power sort and shares gathers
+    inside ``enumerate_us``, ``prepare_us`` the host pad and cast of a
+    block, ``launch_us`` the device call and its output slicing and
+    ``unbatch_us`` the solo Pallas entry's dropping of the instance axis,
+    all three inside ``place_us``.  Counters: ``h2d_bytes`` handed
+    to the device after pad and cast, ``d2h_bytes`` of verdicts brought
+    back, ``launches`` sweep programs enqueued (the Pallas engine
+    enqueues two for a block with ``resilience``, the jax engine one
+    program holding both sweeps), and ``abandoned_rows`` dispatched past
+    the winner's block, whose verdicts the walk never uses.
     """
 
     enumerate_us: float = 0.0
@@ -103,6 +116,16 @@ class WalkStats:
     materialize_us: float = 0.0
     rows: int = 0
     block_sizes: list[int] = dataclasses.field(default_factory=list)
+    search_us: float = 0.0
+    sort_us: float = 0.0
+    gather_us: float = 0.0
+    prepare_us: float = 0.0
+    launch_us: float = 0.0
+    unbatch_us: float = 0.0
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    launches: int = 0
+    abandoned_rows: int = 0
 
     @property
     def total_us(self) -> float:
@@ -119,6 +142,16 @@ class WalkStats:
             "rows": self.rows,
             "n_blocks": len(self.block_sizes),
             "block_sizes": list(self.block_sizes),
+            "search_us": self.search_us,
+            "sort_us": self.sort_us,
+            "gather_us": self.gather_us,
+            "prepare_us": self.prepare_us,
+            "launch_us": self.launch_us,
+            "unbatch_us": self.unbatch_us,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
+            "launches": self.launches,
+            "abandoned_rows": self.abandoned_rows,
         }
 
 
@@ -297,30 +330,27 @@ def _walk_tfs_blocks(
     # longer distinguishes pipelined from eager.
     pipelined = dispatch is not None and getattr(backend, "async_dispatch", True)
     depth = PIPELINE_DEPTH if pipelined else 1
-    now = time.perf_counter
 
     rejects = 0
     winner: tuple[TaskSetCombo, PlacementPlan, int] | None = None
     rank_base = 0
-    # (resolve, ref, rank_base, n_rows) for blocks enqueued but not synced.
+    # (resolve, ref, rank_base, n_rows, block) for blocks enqueued but not synced.
     pending: collections.deque = collections.deque()
 
     def resolve_oldest() -> bool:
         """Sync the oldest in-flight block; True once the winner is known."""
         nonlocal rejects, winner
-        resolve, ref, base, n_rows = pending.popleft()
-        t0 = now()
-        bp = resolve()
-        stats.sync_us += (now() - t0) * 1e6
+        resolve, ref, base, n_rows, block = pending.popleft()
+        with trace.span("sched.sync", "sync_us", block=block):
+            bp = resolve()
         if on_verdict is not None:
             on_verdict(base, bp.feasible, bp.placed_tasks)
         if winner is None:
             r = bp.first_feasible()
             if r >= 0:
-                t0 = now()
-                combo = materialize(ref, r)
-                plan = place_combo(combo, tasks, fleet, **placement_kw)
-                stats.materialize_us += (now() - t0) * 1e6
+                with trace.span("sched.materialize", "materialize_us"):
+                    combo = materialize(ref, r)
+                    plan = place_combo(combo, tasks, fleet, **placement_kw)
                 winner = (combo, plan, base + r)
                 rejects += r  # rows before the first feasible are all rejects
                 if count_all_rejects:
@@ -331,37 +361,41 @@ def _walk_tfs_blocks(
             rejects += int((~bp.feasible).sum())
         return winner is not None
 
+    def abandon_pending() -> None:
+        # Later in-flight blocks hold strictly higher-rank rows; their
+        # verdicts are irrelevant once the winner is known.
+        stats.abandoned_rows += sum(p[3] for p in pending)
+        pending.clear()
+
     stream = iter(block_iter)
-    while True:
-        t0 = now()
-        item = next(stream, None)
-        stats.enumerate_us += (now() - t0) * 1e6
-        if item is None:
-            break
-        shares, ref = item
-        n_rows = len(shares)
-        t0 = now()
-        if dispatch is not None:
-            resolve = dispatch(shares, iis, t_slr_arr, t_cfg_arr, opts)
-        else:
-            bp = backend.place_block(shares, iis, t_slr_arr, t_cfg_arr, opts)
-            resolve = lambda bp=bp: bp  # noqa: E731 — eager backends
-        stats.place_us += (now() - t0) * 1e6
-        stats.rows += n_rows
-        stats.block_sizes.append(n_rows)
-        pending.append((resolve, ref, rank_base, n_rows))
-        rank_base += n_rows
-        while len(pending) >= depth:
-            if resolve_oldest() and not count_all_rejects:
-                # Later in-flight blocks hold strictly higher-rank rows;
-                # their verdicts are irrelevant once the winner is known.
-                pending.clear()
+    with trace.scope(stats):
+        for block in itertools.count():
+            with trace.span("sched.enumerate", "enumerate_us", block=block):
+                item = next(stream, None)
+                if item is None:
+                    break
+                trace.note(rows=len(item[0]))
+            shares, ref = item
+            n_rows = len(shares)
+            with trace.span("sched.dispatch", "place_us", block=block, rows=n_rows):
+                if dispatch is not None:
+                    resolve = dispatch(shares, iis, t_slr_arr, t_cfg_arr, opts)
+                else:
+                    bp = backend.place_block(shares, iis, t_slr_arr, t_cfg_arr, opts)
+                    resolve = lambda bp=bp: bp  # noqa: E731 — eager backends
+            stats.rows += n_rows
+            stats.block_sizes.append(n_rows)
+            pending.append((resolve, ref, rank_base, n_rows, block))
+            rank_base += n_rows
+            while len(pending) >= depth:
+                if resolve_oldest() and not count_all_rejects:
+                    abandon_pending()
+                    break
+            if winner is not None and not count_all_rejects:
                 break
-        if winner is not None and not count_all_rejects:
-            break
-    while pending:
-        if resolve_oldest() and not count_all_rejects:
-            pending.clear()
+        while pending:
+            if resolve_oldest() and not count_all_rejects:
+                abandon_pending()
     if winner is None:
         return None, None, -1, rejects
     return winner[0], winner[1], winner[2], rejects
@@ -450,7 +484,9 @@ def _sorted_tfs_blocks(feas: FeasibilityResult, sizes: Iterator[int]):
     boundaries (and therefore all rank/reject bookkeeping) are exactly
     those of a per-block gather; only the copy granularity changes.
     """
-    order = feas.tfs_indices_by_power()
+    with trace.span("sched.tfs_sort", "sort_us"):
+        order = feas.tfs_indices_by_power()
+        trace.note(tfs_rows=order.size)
     lo = 0
     buf = None
     buf_lo = 0
@@ -459,7 +495,8 @@ def _sorted_tfs_blocks(feas: FeasibilityResult, sizes: Iterator[int]):
         if buf is None or hi > buf_lo + buf.shape[0]:
             buf_lo = lo
             end = max(hi, min(lo + _GATHER_CHUNK, order.size))
-            buf = feas.shares_matrix(order[lo:end])
+            with trace.span("sched.gather", "gather_us", rows=end - lo):
+                buf = feas.shares_matrix(order[lo:end])
         yield buf[lo - buf_lo : hi - buf_lo], order[lo:hi]
         lo = hi
 
@@ -594,9 +631,8 @@ def _walk_many_tfs_blocks(
     # spell out the dispatch surface (async_dispatch = False) get depth 1.
     has_async = has_dispatch and getattr(backend, "async_dispatch", True)
     depth = PIPELINE_DEPTH if has_async else 1
-    now = time.perf_counter
 
-    # (raw, resolver, entries) per round; entries = [(walk, ref, base, n_rows)].
+    # (raw, resolver, entries, round) per round; entries = [(walk, ref, base, n_rows)].
     pending: collections.deque = collections.deque()
 
     def apply_verdict(w, ref, base, n_rows, has_feas, first, n_feas, feas_row):
@@ -607,14 +643,16 @@ def _walk_many_tfs_blocks(
         ``count_all_rejects`` actually needs the per-row bits.
         """
         if w.done:
-            return  # abandoned in-flight block of a finished walk
+            # Abandoned in-flight block of a finished walk: synced with
+            # its round, never read.
+            stats.abandoned_rows += n_rows
+            return
         if w.winner is None:
             if has_feas:
                 r = first
-                t0 = now()
-                combo = w.materialize(ref, r)
-                plan = place_combo(combo, w.tasks, w.fleet, **placement_kw)
-                stats.materialize_us += (now() - t0) * 1e6
+                with trace.span("sched.materialize", "materialize_us"):
+                    combo = w.materialize(ref, r)
+                    plan = place_combo(combo, w.tasks, w.fleet, **placement_kw)
                 w.winner = (combo, plan, base + r)
                 w.rejects += r
                 if count_all_rejects:
@@ -627,10 +665,9 @@ def _walk_many_tfs_blocks(
             w.rejects += n_rows - n_feas
 
     def resolve_round() -> None:
-        raw, resolver, entries = pending.popleft()
-        t0 = now()
-        results = resolver()
-        stats.sync_us += (now() - t0) * 1e6
+        raw, resolver, entries, block = pending.popleft()
+        with trace.span("sched.sync", "sync_us", block=block):
+            results = resolver()
         if raw:
             # Raw surface: one vectorized reduction pass over the round's
             # (B', Rp) verdict block instead of B trimmed result objects;
@@ -658,41 +695,45 @@ def _walk_many_tfs_blocks(
                 )
 
     live = list(walks)
-    while live:
-        entries = []
-        blocks = []
-        t0 = now()
-        for w in live[:]:
-            if w.done:
-                live.remove(w)
-                continue
-            item = next(w.stream, None)
-            if item is None:
-                live.remove(w)  # stream exhausted; verdicts may be in flight
-                continue
-            shares, ref = item
-            n_rows = len(shares)
-            entries.append((w, ref, w.rank_base, n_rows))
-            blocks.append((shares, w.iis, w.slr_arr, w.cfg_arr))
-            w.rank_base += n_rows
-            stats.rows += n_rows
-            stats.block_sizes.append(n_rows)
-        stats.enumerate_us += (now() - t0) * 1e6
-        if not entries:
-            break
-        t0 = now()
-        batch = InstanceBatch.pack(blocks)
-        raw = raw_hook(batch, opts, shard=shard) if raw_hook is not None else None
-        if raw is not None:
-            pending.append((True, raw, entries))
-        else:
-            resolver = dispatch_instance_blocks(backend, batch, opts, shard=shard)
-            pending.append((False, resolver, entries))
-        stats.place_us += (now() - t0) * 1e6
-        while len(pending) >= depth:
+    with trace.scope(stats):
+        for block in itertools.count():
+            if not live:
+                break
+            with trace.span("sched.round", block=block):
+                entries = []
+                blocks = []
+                with trace.span("sched.enumerate", "enumerate_us", block=block):
+                    for w in live[:]:
+                        if w.done:
+                            live.remove(w)
+                            continue
+                        item = next(w.stream, None)
+                        if item is None:
+                            live.remove(w)  # stream exhausted; verdicts may be in flight
+                            continue
+                        shares, ref = item
+                        n_rows = len(shares)
+                        entries.append((w, ref, w.rank_base, n_rows))
+                        blocks.append((shares, w.iis, w.slr_arr, w.cfg_arr))
+                        w.rank_base += n_rows
+                        stats.rows += n_rows
+                        stats.block_sizes.append(n_rows)
+                if not entries:
+                    break
+                n_rows = sum(e[3] for e in entries)
+                trace.note(instances=len(entries), rows=n_rows)
+                with trace.span("sched.dispatch", "place_us", block=block, rows=n_rows):
+                    batch = InstanceBatch.pack(blocks)
+                    raw = raw_hook(batch, opts, shard=shard) if raw_hook is not None else None
+                    if raw is not None:
+                        pending.append((True, raw, entries, block))
+                    else:
+                        resolver = dispatch_instance_blocks(backend, batch, opts, shard=shard)
+                        pending.append((False, resolver, entries, block))
+                while len(pending) >= depth:
+                    resolve_round()
+        while pending:
             resolve_round()
-    while pending:
-        resolve_round()
 
 
 class PADPSFRScheduler:
@@ -745,6 +786,12 @@ class PADPSFRScheduler:
         if self.exhaustive is not None:
             return self.exhaustive
         return combo_count(tasks) <= self.exhaustive_limit
+
+    @staticmethod
+    def _search(tasks: Sequence[Task], fleet: FleetSpec, resilience: int) -> FeasibilityResult:
+        """The eq-7 search (Alg 1) over the whole TSS, as a span."""
+        with trace.span("sched.eq7_search", "search_us", tss=combo_count(tasks)):
+            return search_feasible(tasks, fleet, resilience=resilience)
 
     def schedule(
         self,
@@ -801,6 +848,22 @@ class PADPSFRScheduler:
             (True, (0, 1), 11.0)
         """
         tasks = tuple(tasks)
+        # A recorded walk makes ``walk_stats`` current around its own
+        # blocks only: the replanner's direct backend probes are no block
+        # of a walk, and must not add launch time that no place_us holds.
+        with trace.call(
+            "sched.schedule", None if record_state else walk_stats,
+            n_t=len(tasks), n_f=self.fleet.n_f, engine=self.engine,
+        ):
+            return self._schedule(
+                tasks, count_all_rejects, walk_stats, record_state, record_exhaustive,
+                placement_kw,
+            )
+
+    def _schedule(
+        self, tasks, count_all_rejects, walk_stats, record_state, record_exhaustive,
+        placement_kw,
+    ) -> ScheduleResult:
         resilience = _validate_resilience(placement_kw)
         if resilience >= self.fleet.n_f and tasks:
             return _resilience_infeasible_result(tasks)
@@ -817,10 +880,9 @@ class PADPSFRScheduler:
                 exhaustive=record_exhaustive,
                 **placement_kw,
             )
-        use_exhaustive = self._use_exhaustive(tasks)
         feas = (
-            search_feasible(tasks, self.fleet, resilience=resilience)
-            if use_exhaustive
+            self._search(tasks, self.fleet, resilience)
+            if self._use_exhaustive(tasks)
             else None
         )
         if self.engine == "scalar":
@@ -910,7 +972,7 @@ class PADPSFRScheduler:
         if n_batch > 1:
             sizes = _coalesced_sizes(sizes, max(1, _MANY_ROUND_ROWS // n_batch))
         if self._use_exhaustive(tasks):
-            feas = search_feasible(tasks, fleet, resilience=resilience)
+            feas = self._search(tasks, fleet, resilience)
             stream = _sorted_tfs_blocks(feas, sizes)
             materialize = lambda idx, r: feas.combo_at(int(idx[r]))  # noqa: E731
         else:
@@ -989,6 +1051,17 @@ class PADPSFRScheduler:
         insts = [self._coerce_instance(x) for x in instances]
         if not insts:
             return []
+        with trace.call(
+            "sched.schedule_many", walk_stats,
+            instances=len(insts), n_f=self.fleet.n_f, engine=self.engine,
+        ):
+            return self._schedule_many(
+                insts, shard, count_all_rejects, walk_stats, placement_kw
+            )
+
+    def _schedule_many(
+        self, insts, shard, count_all_rejects, walk_stats, placement_kw
+    ) -> list[ScheduleResult]:
         resilience = _validate_resilience(placement_kw)
         if self.engine == "scalar":
             # The row-at-a-time oracle has no block surface to batch; a

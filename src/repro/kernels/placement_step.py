@@ -41,6 +41,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import trace
 from .ref import _PLACE_EPS
 
 __all__ = ["placement_sweep_pallas", "placement_sweep_batch_pallas"]
@@ -189,7 +190,9 @@ def placement_sweep_pallas(
         block_rows=block_rows,
         interpret=interpret,
     )
-    return tuple(o[0] for o in outs)
+    # Dropping the instance axis: one eager device op an output.
+    with trace.span("sched.unbatch", "unbatch_us"):
+        return tuple(o[0] for o in outs)
 
 
 def placement_sweep_batch_pallas(
@@ -227,17 +230,23 @@ def placement_sweep_batch_pallas(
     # Pad where the arrays live: host arrays from the backends stay on the
     # host, so no eager device op compiles per distinct (B, R).
     xp = np if isinstance(shares, np.ndarray) else jnp
-    shares = xp.pad(shares, ((0, Bp - B), (0, Rp - R), (0, 0)))
-    iis, t_slr, t_cfg = (xp.pad(a, ((0, Bp - B), (0, 0))) for a in (iis, t_slr, t_cfg))
-    eff = xp.pad(
-        xp.stack([xp.asarray(n_t_eff), xp.asarray(n_f_eff)], axis=1).astype(xp.int32),
-        ((0, Bp - B), (0, 0)),
-    )
-    feas, placed, n_splits, devices_used = _placement_sweep_batch_padded(
-        shares, iis, t_slr, t_cfg, eff, resume_cost,
-        repay_init=repay_init, block_rows=block_rows, interpret=interpret,
-    )
-    return tuple(o[:B, :R] for o in (feas, placed, n_splits, devices_used))
+    with trace.span("sched.prepare", "prepare_us"):
+        shares = xp.pad(shares, ((0, Bp - B), (0, Rp - R), (0, 0)))
+        iis, t_slr, t_cfg = (
+            xp.pad(a, ((0, Bp - B), (0, 0))) for a in (iis, t_slr, t_cfg)
+        )
+        eff = xp.pad(
+            xp.stack([xp.asarray(n_t_eff), xp.asarray(n_f_eff)], axis=1).astype(xp.int32),
+            ((0, Bp - B), (0, 0)),
+        )
+    trace.note(padded_rows=Rp)
+    # Host arrays are copied to the device by the call.
+    with trace.launch((shares, iis, t_slr, t_cfg, eff) if xp is np else ()):
+        outs = _placement_sweep_batch_padded(
+            shares, iis, t_slr, t_cfg, eff, resume_cost,
+            repay_init=repay_init, block_rows=block_rows, interpret=interpret,
+        )
+        return tuple(o[:B, :R] for o in outs)
 
 
 @functools.partial(

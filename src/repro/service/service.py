@@ -36,7 +36,8 @@ import dataclasses
 import time
 from typing import Iterable, Sequence
 
-from ..core.scheduler import PADPSFRScheduler, ScheduleInstance, ScheduleResult
+from .. import trace
+from ..core.scheduler import PADPSFRScheduler, ScheduleInstance, ScheduleResult, WalkStats
 from ..core.task import DeviceProfile, FleetSpec, Task
 from .events import DeviceFailure, DeviceRecovery, Event, TaskArrival, TaskExit
 
@@ -306,6 +307,7 @@ class SchedulerService:
         arrivals: Sequence[Task],
         *,
         shard: int | str | None = None,
+        walk_stats: WalkStats | None = None,
     ) -> list[ScheduleResult]:
         """Answer "what would admitting each of these cost?" in one sweep.
 
@@ -322,14 +324,24 @@ class SchedulerService:
         This is the service-side fleet-parallel entry point: a placement
         controller probing "which of these 64 queued jobs fits
         cheapest?" pays one batched walk instead of 64 solo walks.
+        ``walk_stats``, when given, collects the batched walk's phases
+        (:class:`~repro.core.scheduler.WalkStats`).
         """
-        instances = [
-            ScheduleInstance(tasks=self._tasks + (a,), fleet=self.fleet)
-            for a in arrivals
-        ]
-        return self._sched.schedule_many(
-            instances, shard=shard, **self.placement_kw
-        )
+        with trace.call(
+            "sched.what_if_many", walk_stats,
+            instances=len(arrivals), n_t=len(self._tasks) + 1, n_f=self.fleet.n_f,
+            engine=self._sched.engine,
+        ):
+            instances = [
+                ScheduleInstance(tasks=self._tasks + (a,), fleet=self.fleet)
+                for a in arrivals
+            ]
+            # Only when given: an explicit None would replace the WalkStats
+            # that a wrapper of ``schedule_many`` may supply.
+            extra = {} if walk_stats is None else {"walk_stats": walk_stats}
+            return self._sched.schedule_many(
+                instances, shard=shard, **self.placement_kw, **extra
+            )
 
     # -- internals ------------------------------------------------------
     def _cache_key(self, tasks: Sequence[Task]) -> tuple:

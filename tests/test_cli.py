@@ -1,6 +1,5 @@
 """CLI entry-point smoke tests (subprocess)."""
 
-import json
 import os
 import subprocess
 import sys
@@ -48,156 +47,6 @@ def test_serve_cli():
     ])
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "generated" in proc.stdout
-
-
-def _bench_artifact(
-    us_by_name, rows_per_s=None, crossover=None, replan=None, resilience=None,
-    churn=None,
-):
-    doc = {
-        "benchmark": "scheduler_scale",
-        "rows": [{"name": n, "us": v, "derived": ""} for n, v in us_by_name.items()],
-    }
-    if rows_per_s is not None:
-        doc["backend_sweep"] = {
-            "sizes": [1000],
-            "us": {},
-            "rows_per_s": rows_per_s,
-            "numpy_jax_crossover_rows": crossover,
-        }
-    if replan is not None:
-        doc["replan"] = replan
-    if resilience is not None:
-        doc["resilience"] = resilience
-    if churn is not None:
-        doc["churn"] = churn
-    return doc
-
-
-def test_trend_report_cli(tmp_path):
-    a = tmp_path / "BENCH_old.json"
-    b = tmp_path / "BENCH_new.json"
-    a.write_text(json.dumps(_bench_artifact(
-        {"alg2_batched_tfs4096": 1000.0},
-        rows_per_s={"numpy": {"1000": 5e5}, "jax": {"1000": 4e5}},
-    )))
-    b.write_text(json.dumps(_bench_artifact(
-        {"alg2_batched_tfs4096": 800.0, "only_in_new": 5.0},
-        rows_per_s={"numpy": {"1000": 5e5}, "jax": {"1000": 8e5}},
-        crossover=1000,
-    )))
-    out = tmp_path / "trend.json"
-    proc = _run(["benchmarks.trend_report", str(a), str(b), "--json", str(out)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "alg2_batched_tfs4096" in proc.stdout
-    assert "-20.0%" in proc.stdout  # 1000us -> 800us
-    assert "jax @ 1000 rows" in proc.stdout
-    trend = json.loads(out.read_text())
-    assert trend["rows"]["alg2_batched_tfs4096"]["delta_pct"] == pytest.approx(-20.0)
-    assert trend["rows"]["only_in_new"]["us"] == [None, 5.0]
-    assert trend["numpy_jax_crossover_rows"] == [None, 1000]
-
-    # fewer than two artifacts is a usage error
-    proc = _run(["benchmarks.trend_report", str(a)])
-    assert proc.returncode != 0
-
-
-def test_trend_report_replan_rows_graceful(tmp_path):
-    """Artifacts predating the delta-replan benchmark must not crash the
-    trend report — clear note, exit 0 (the CI bench-smoke contract)."""
-    old = tmp_path / "BENCH_old.json"
-    new = tmp_path / "BENCH_new.json"
-    old.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 1000.0})))
-    new.write_text(json.dumps(_bench_artifact(
-        {"alg2_batched_tfs4096": 900.0, "replan_warm_11t": 150.0},
-        replan={"cold_us": 2.0e6, "warm_us": 1.6e5, "speedup": 12.5,
-                "bit_identical": True},
-    )))
-
-    # old + new: replan trend renders, with a note about the older file
-    proc = _run(["benchmarks.trend_report", str(old), str(new)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "delta replan" in proc.stdout
-    assert "12.5x" in proc.stdout
-    assert "predates the delta-replan benchmark" in proc.stdout
-
-    # two pre-replan artifacts: skipped with a message, still exit 0
-    old2 = tmp_path / "BENCH_old2.json"
-    old2.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 950.0})))
-    proc = _run(["benchmarks.trend_report", str(old), str(old2)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "no artifact carries replan rows" in proc.stdout
-
-
-def test_trend_report_resilience_rows_graceful(tmp_path):
-    """Artifacts predating the resilience benchmark must not crash the
-    trend report — same contract as the replan/fleet_parallel sections."""
-    old = tmp_path / "BENCH_old.json"
-    new = tmp_path / "BENCH_new.json"
-    old.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 1000.0})))
-    new.write_text(json.dumps(_bench_artifact(
-        {"alg2_batched_tfs4096": 900.0, "resilience_k1_4t4f": 650.0},
-        resilience={
-            "instance": "4t4f",
-            "points": {
-                "k0": {"power": 8.0, "premium_pct": 0.0, "us": 400.0},
-                "k1": {"power": 20.0, "premium_pct": 150.0, "us": 650.0},
-                "k2": {"power": 32.0, "premium_pct": 300.0, "us": 550.0},
-            },
-            "faultsim": {"k1_survives_all_seeds": True},
-        },
-    )))
-
-    # old + new: resilience trend renders, with a note about the older file
-    proc = _run(["benchmarks.trend_report", str(old), str(new)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "k-fault tolerance" in proc.stdout
-    assert "150.0%" in proc.stdout
-    assert "predates the resilience benchmark" in proc.stdout
-
-    # two pre-resilience artifacts: skipped with a message, still exit 0
-    old2 = tmp_path / "BENCH_old2.json"
-    old2.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 950.0})))
-    proc = _run(["benchmarks.trend_report", str(old), str(old2)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "no artifact carries resilience rows" in proc.stdout
-
-
-def test_trend_report_churn_rows_graceful(tmp_path):
-    """Artifacts predating the churn benchmark must not crash the trend
-    report — same contract as the replan/resilience sections."""
-    old = tmp_path / "BENCH_old.json"
-    new = tmp_path / "BENCH_new.json"
-    old.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 1000.0})))
-    new.write_text(json.dumps(_bench_artifact(
-        {"alg2_batched_tfs4096": 900.0, "churn_exit_warm_10t": 250.0},
-        churn={
-            "deep_instance": "10t",
-            "exit": {"chosen_rank": 58045, "cold_us": 3.1e5,
-                     "warm_us": 2.5e4, "speedup": 12.4, "bit_identical": True},
-            "failure": {"chosen_rank": 58045, "cold_us": 3.2e5,
-                        "warm_us": 3.2e4, "speedup": 10.0,
-                        "bit_identical": True},
-            "trace": {"n_events": 200, "n_solved": 156,
-                      "warm_hit_rate": 0.95, "rerecords": 60,
-                      "speedup": 0.7},
-        },
-    )))
-
-    # old + new: churn trend renders, with a note about the older file
-    proc = _run(["benchmarks.trend_report", str(old), str(new)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "service churn" in proc.stdout
-    assert "12.4x" in proc.stdout
-    assert "95.0%" in proc.stdout
-    assert "predates the churn benchmark" in proc.stdout
-
-    # two pre-churn artifacts: skipped with a message, still exit 0
-    old2 = tmp_path / "BENCH_old2.json"
-    old2.write_text(json.dumps(_bench_artifact({"alg2_batched_tfs4096": 950.0})))
-    proc = _run(["benchmarks.trend_report", str(old), str(old2)])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "no artifact carries churn rows" in proc.stdout
 
 
 @pytest.mark.slow
