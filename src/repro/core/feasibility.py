@@ -52,6 +52,11 @@ __all__ = [
     "config_overhead_lower_bound",
 ]
 
+# TSS positions sampled to place the boundaries of the lazy power order
+# (FeasibilityResult.tfs_power_segment), and the Weyl step that spreads them.
+_POWER_SAMPLE = 8192
+_GOLDEN = 0.6180339887498949
+
 
 @dataclasses.dataclass
 class FeasibilityResult:
@@ -68,7 +73,7 @@ class FeasibilityResult:
 
     @property
     def n_tfs(self) -> int:
-        return int(self.fit_mask.sum())
+        return int(np.count_nonzero(self.fit_mask))
 
     @property
     def n_tnfs(self) -> int:
@@ -123,12 +128,82 @@ class FeasibilityResult:
         Exact-power ties are broken by ascending flat (C-order TSS) index
         — the stable sort below — so the ordering is deterministic and
         matches the streamed engines (``iter_feasible_pruned*``) exactly.
+
+        This is the reference order: the scalar engine walks it, and the
+        block walk, which orders only the prefix it reaches
+        (:meth:`tfs_power_segment`), is tested against it.
         """
         tfs = np.flatnonzero(self.fit_mask)
         # Stable sort: ties broken by TSS enumeration (flat-index) order,
         # matching the paper's "Assc. Sort on TFS" over the generated list.
         order = np.argsort(self.total_power[tfs], kind="stable")
         return tfs[order]
+
+    def _power_sample(self) -> np.ndarray:
+        """Sorted total powers of the TFS rows at ``_POWER_SAMPLE`` TSS
+        positions (every position when |TSS| is no larger), NaN left out:
+        the order statistics that place :meth:`tfs_power_segment`'s
+        boundaries.  Computed once.
+
+        The positions are a Weyl sequence, spread over the TSS without
+        the aliasing a fixed stride has with the C-order grid of variants.
+        """
+        cached = getattr(self, "_power_sample_cache", None)
+        if cached is None:
+            n = self.n_combos
+            if n <= _POWER_SAMPLE:
+                pos = np.arange(n)
+            else:
+                pos = (np.arange(_POWER_SAMPLE) * _GOLDEN % 1.0 * n).astype(np.int64)
+            p = self.total_power[pos][self.fit_mask[pos]]
+            cached = np.sort(p[~np.isnan(p)])
+            self._power_sample_cache = cached
+        return cached
+
+    def tfs_power_segment(self, above: float, want: int) -> tuple[np.ndarray, float]:
+        """The next segment of the power order past ``above``.
+
+        Returns the flat indices of every TFS row whose total power lies
+        in ``(above, q]``, in :meth:`tfs_indices_by_power` order, and
+        ``q``.  Start from ``above=-inf`` and pass each ``q`` back: the
+        segments then concatenate to exactly the full order.  Every row
+        at or below ``q`` precedes every row above it, and a stable sort
+        of a segment's rows, taken in ascending flat index, breaks ties
+        by flat index; the comparisons are exact float64.
+
+        ``q`` is an order statistic of a fixed sample of TFS powers, set
+        so the segment holds at least ``want`` rows, with some margin;
+        when it comes up short a further segment is appended, so a tie
+        group is never split.  Once about half of what remains is wanted
+        the segment takes every remaining row and ``q`` is ``inf``; NaN
+        powers, which no comparison admits, come last there, as in the
+        full sort.  A walk that reads a prefix thus orders a little more
+        than that prefix, not the whole TFS.
+        """
+        power, fit = self.total_power, self.fit_mask
+        sample = self._power_sample()
+        rows_per_point = self.n_tfs / max(sample.size, 1)
+        parts = [np.empty(0, dtype=np.int64)]
+        got = 0
+        while got < want and above != np.inf:
+            j0 = int(np.searchsorted(sample, above, side="right"))
+            # Sample points the wanted rows span, plus two standard
+            # deviations of that count, so one segment nearly always does.
+            k = (want - got) / rows_per_point
+            k = int(np.ceil(k + 2.0 * np.sqrt(k))) + 1
+            q = np.inf if j0 + 2 * k >= sample.size else float(sample[j0 + k])
+            if q == np.inf:
+                sel = fit if above == -np.inf else fit & ~(power <= above)
+            else:
+                sel = power <= q
+                sel &= fit
+                if above != -np.inf:
+                    sel &= power > above
+            idx = np.flatnonzero(sel)
+            parts.append(idx[np.argsort(power[idx], kind="stable")])
+            got += idx.size
+            above = q
+        return np.concatenate(parts), above
 
     def iter_tfs_by_power(self) -> Iterator[TaskSetCombo]:
         for i in self.tfs_indices_by_power():

@@ -29,6 +29,8 @@ bookkeeping; a backend only ever sees a shares matrix.  The two block
 producers are interchangeable by construction:
 
 * exhaustive — ``FeasibilityResult.shares_matrix`` gathers a slice of
+  the power order, which the walk builds lazily, segment by segment
+  (``FeasibilityResult.tfs_power_segment``), and which equals
   ``tfs_indices_by_power()``;
 * streaming — ``feasibility.iter_feasible_pruned_blocks`` yields
   :class:`repro.core.feasibility.ComboBlock` batches straight from the

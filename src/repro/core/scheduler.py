@@ -106,8 +106,11 @@ class WalkStats:
     to the device after pad and cast, ``d2h_bytes`` of verdicts brought
     back, ``launches`` sweep programs enqueued (the Pallas engine
     enqueues two for a block with ``resilience``, the jax engine one
-    program holding both sweeps), and ``abandoned_rows`` dispatched past
-    the winner's block, whose verdicts the walk never uses.
+    program holding both sweeps), ``abandoned_rows`` dispatched past
+    the winner's block, whose verdicts the walk never uses, and, on the
+    exhaustive path, ``tfs_rows`` the TFS size of each walk and
+    ``sorted_rows`` the rows the walk put in power order (the lazy sort
+    orders only the prefix it reads).
     """
 
     enumerate_us: float = 0.0
@@ -126,6 +129,8 @@ class WalkStats:
     d2h_bytes: int = 0
     launches: int = 0
     abandoned_rows: int = 0
+    tfs_rows: int = 0
+    sorted_rows: int = 0
 
     @property
     def total_us(self) -> float:
@@ -152,6 +157,8 @@ class WalkStats:
             "d2h_bytes": self.d2h_bytes,
             "launches": self.launches,
             "abandoned_rows": self.abandoned_rows,
+            "tfs_rows": self.tfs_rows,
+            "sorted_rows": self.sorted_rows,
         }
 
 
@@ -476,22 +483,42 @@ def _coalesced_sizes(sizes: Iterator[int], rcap: int) -> Iterator[int]:
 def _sorted_tfs_blocks(feas: FeasibilityResult, sizes: Iterator[int]):
     """Yield ``(shares_rows, idx_rows)`` blocks of the power-sorted TFS.
 
+    The TFS is put in power order lazily, as far as the walk reads it:
+    when a block reaches past the rows ordered so far, the next segment
+    of the order (:meth:`FeasibilityResult.tfs_power_segment`) is
+    appended, aimed at the rows the block lacks and at least twice the
+    previous aim, so segments grow geometrically.  The segments laid end
+    to end are exactly :meth:`FeasibilityResult.tfs_indices_by_power`, so
+    a walk that stops at a shallow winner sorts a short prefix, not the
+    whole TFS, and one that runs to the end (``count_all_rejects``) pays
+    a few extra mask passes over the TSS.
+
     Shares are gathered through :meth:`FeasibilityResult.shares_matrix`
-    in chunks of ``_GATHER_CHUNK`` sorted rows and sliced per block, so a
-    small fixed block size pays one fancy-indexed gather per few hundred
-    blocks instead of one per block — the gather's fixed Python cost was
-    the dominant per-block overhead of dispatch-heavy walks.  Block
-    boundaries (and therefore all rank/reject bookkeeping) are exactly
-    those of a per-block gather; only the copy granularity changes.
+    in chunks of up to ``_GATHER_CHUNK`` ordered rows and sliced per
+    block, so a small fixed block size pays one fancy-indexed gather per
+    few hundred blocks instead of one per block — the gather's fixed
+    Python cost was the dominant per-block overhead of dispatch-heavy
+    walks.  Block boundaries (and therefore all rank/reject bookkeeping)
+    are exactly those of a per-block gather; only the copy granularity
+    changes.
     """
-    with trace.span("sched.tfs_sort", "sort_us"):
-        order = feas.tfs_indices_by_power()
-        trace.note(tfs_rows=order.size)
+    n_tfs = feas.n_tfs
+    trace.count("tfs_rows", n_tfs)
+    order = np.empty(0, dtype=np.int64)
+    above = -np.inf
+    aim = 0
     lo = 0
     buf = None
     buf_lo = 0
-    while lo < order.size:
-        hi = min(lo + next(sizes), order.size)
+    while lo < n_tfs:
+        hi = min(lo + next(sizes), n_tfs)
+        if hi > order.size:
+            aim = max(hi - order.size, 2 * aim)
+            with trace.span("sched.tfs_sort", "sort_us"):
+                part, above = feas.tfs_power_segment(above, aim)
+                order = np.concatenate([order, part])
+                trace.note(rows=part.size)
+            trace.count("sorted_rows", part.size)
         if buf is None or hi > buf_lo + buf.shape[0]:
             buf_lo = lo
             end = max(hi, min(lo + _GATHER_CHUNK, order.size))

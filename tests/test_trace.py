@@ -42,7 +42,8 @@ def test_new_fields_are_parts_of_the_old(engine, many):
     assert ws.prepare_us + ws.launch_us + ws.unbatch_us <= ws.place_us
     d = ws.as_dict()
     for key in ("search_us", "sort_us", "gather_us", "prepare_us", "launch_us", "unbatch_us",
-                "h2d_bytes", "d2h_bytes", "launches", "abandoned_rows"):
+                "h2d_bytes", "d2h_bytes", "launches", "abandoned_rows", "tfs_rows",
+                "sorted_rows"):
         assert d[key] == getattr(ws, key)
     # The old fields keep their meaning: the walk's four phases.
     assert ws.total_us == ws.enumerate_us + ws.place_us + ws.sync_us + ws.materialize_us
@@ -72,6 +73,40 @@ def test_blocks_past_the_winner_are_abandoned_on_the_pipelined_engine(many):
     # never read.
     assert ws.abandoned_rows > 0
     assert ws.abandoned_rows == ws.rows - ws.block_sizes[0] * (3 if many else 1)
+
+
+def test_a_shallow_walk_sorts_a_small_share_of_the_tfs():
+    ws = _walk("numpy", False)
+    # Example 1: 620 TFS rows, the winner at rank 4 in the first block of
+    # 64; the lazy sort orders that block's rows and a little past them.
+    assert ws.tfs_rows == 620
+    assert 64 <= ws.sorted_rows <= ws.tfs_rows // 4
+
+
+@pytest.mark.parametrize("many", [False, True], ids=["schedule", "schedule_many"])
+def test_a_walk_to_the_end_sorts_every_row_segment_by_segment(many, monkeypatch):
+    from repro.core.feasibility import FeasibilityResult
+
+    segments = []
+    segment = FeasibilityResult.tfs_power_segment
+
+    def counted(self, above, want):
+        segments.append(want)
+        return segment(self, above, want)
+
+    monkeypatch.setattr(FeasibilityResult, "tfs_power_segment", counted)
+    ws = WalkStats()
+    sched = PADPSFRScheduler(example1_fleet(), engine="numpy", block_size=16)
+    if many:
+        sched.schedule_many([example1_tasks()] * 3, walk_stats=ws, count_all_rejects=True)
+    else:
+        sched.schedule(example1_tasks(), walk_stats=ws, count_all_rejects=True)
+    n = 3 if many else 1
+    assert ws.sorted_rows == ws.tfs_rows == 620 * n
+    # The sort is split across blocks, each segment inside a block's
+    # enumerate span.
+    assert len(segments) > n
+    assert 0 < ws.sort_us + ws.gather_us <= ws.enumerate_us
 
 
 @pytest.mark.parametrize("engine,rows_p,eff_bytes", [("jax", 1024, 0), ("pallas", 1024, 8)])
